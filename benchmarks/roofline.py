@@ -13,14 +13,11 @@ stacked backend (``kernels.backends``) and reports
     achieved_gbps  = bytes_per_lookup / ns_per_lookup
     roofline_frac  = achieved_gbps / peak_gbps
 
-Peak bandwidth comes from ``PEAK_GBPS`` keyed by ``jax.default_backend()``
-(override with env ``ROOFLINE_PEAK_<PLATFORM>_GBPS``); the CPU default is
-a deliberately round single-socket figure — on the shared CI runner the
-*trajectory* of ``roofline_frac`` is the signal, not its absolute value.
-Interpret-mode Pallas rows are dispatch-overhead measurements (the
-interpreter re-walks the kernel per block); they track regressions in the
-fused path's plumbing, and only a real-TPU run makes its fraction
-meaningful against HBM peak.
+Peak bandwidth comes from ``PEAK_GBPS``, keyed by the device's
+``device_kind`` as JAX reports it, each entry with its published source.
+A device that is not in the table is an error, not a default: a roofline
+share only means something against the chip it ran on, so a CPU run
+fails here instead of timing the host.
 
 Records are appended to ``results/``-bound ``BENCH_lookup.json``
 schema-additively (``workload: "roofline"``, carrying
@@ -32,22 +29,28 @@ the serve rows.
 from __future__ import annotations
 
 import json
-import os
 
 import numpy as np
 
-# defaults per jax platform, GB/s; absolute truth only matters on real
-# hardware — override via ROOFLINE_PEAK_<PLATFORM>_GBPS
-PEAK_GBPS = {"cpu": 20.0, "gpu": 900.0, "tpu": 819.0}
+# peak HBM bandwidth of one chip, GB/s, keyed by ``jax.Device.device_kind``
+PEAK_GBPS = {
+    # TPU v5e: 16 GB of HBM at 819 GB/s — Google Cloud documentation,
+    # "TPU v5e" (system architecture)
+    "TPU v5 lite": 819.0,
+}
 
 EPS = 64
 
 
-def peak_gbps(platform: str) -> float:
-    env = os.environ.get(f"ROOFLINE_PEAK_{platform.upper()}_GBPS")
-    if env is not None:
-        return float(env)
-    return PEAK_GBPS.get(platform, PEAK_GBPS["cpu"])
+def peak_gbps(device_kind: str) -> float:
+    """Published peak bandwidth of ``device_kind``; raises for a device
+    the table does not know."""
+    try:
+        return PEAK_GBPS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peak bandwidth for device kind {device_kind!r}; "
+            "add it to PEAK_GBPS with its source") from None
 
 
 def bytes_per_lookup(sp, probe: str) -> int:
@@ -103,8 +106,7 @@ def run(out_rows: list[str] | None = None) -> list[str]:
     rows = out_rows if out_rows is not None else []
     rows.append("roofline,dataset,n,eps,backend,probe,ns_per_lookup,"
                 "bytes_per_lookup,achieved_gbps,peak_gbps,roofline_frac")
-    platform = jax.default_backend()
-    peak = peak_gbps(platform)
+    peak = peak_gbps(jax.devices()[0].device_kind)
     stacked = [b for b in backend_names()
                if get_backend(b).stacked_factory is not None]
     records: list[dict] = []

@@ -4,8 +4,9 @@
 
 Sections: fig2 (build/size), fig3 (lookup/size), autotune (vs grid search),
 kernel (device lookup path), serve (PlexService per-backend throughput),
-build_scale (parallel sharded build throughput), roofline (from dry-run
-artifacts, if present).
+build_scale (parallel sharded build throughput), roofline (bytes per
+lookup against the chip's peak bandwidth; fails on a device without a
+published peak, the CPU included).
 
 Each section's CSV rows are also written to ``BENCH_<section>.json`` so CI
 can archive per-PR artifacts; the serve and build_scale sections
@@ -33,6 +34,9 @@ def main() -> None:
     if args.quick and "BENCH_N" not in os.environ:
         os.environ["BENCH_N"] = "60000"
         os.environ["BENCH_QUERIES"] = "40000"
+
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache(pathlib.Path(__file__).resolve().parents[1])
 
     # imports AFTER env so common.py picks BENCH_N up
     from . import autotune_grid, build_scale, fig2_build, fig3_lookup

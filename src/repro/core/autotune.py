@@ -138,9 +138,11 @@ class TuneResult:
 def tune(spline: Spline, data_keys: np.ndarray, *,
          r_max_radix: int = 24, r_max_cht: int = 16, delta_max: int = 1024,
          budget_bytes: int | None = None, sample: int | None = None,
-         rng: np.random.Generator | None = None) -> TuneResult:
+         rng: np.random.Generator | None = None,
+         allow_cht: bool = True) -> TuneResult:
     """Pick the best radix layer under ``bytes <= budget`` (paper §3 PLEX:
-    the default budget is the spline size, so PLEX is at most 2x the spline)."""
+    the default budget is the spline size, so PLEX is at most 2x the spline).
+    ``allow_cht=False`` restricts the choice to flat radix tables."""
     budget = spline.size_bytes if budget_bytes is None else budget_bytes
     dk = np.asarray(data_keys, dtype=np.uint64)
     if sample is not None and dk.size > sample:
@@ -160,7 +162,8 @@ def tune(spline: Spline, data_keys: np.ndarray, *,
     feasible = c_byt <= budget
     masked = np.where(feasible, c_lam, np.inf)
     r_c, d_c = np.unravel_index(np.argmin(masked), masked.shape)
-    if masked[r_c, d_c] < best[3]:   # strict: ties fall back to radix table
+    # strict: ties fall back to radix table
+    if allow_cht and masked[r_c, d_c] < best[3]:
         best = ("cht", int(r_c), int(d_c), float(masked[r_c, d_c]),
                 int(c_byt[r_c, d_c]))
     return TuneResult(kind=best[0], r=best[1], delta=best[2],

@@ -42,6 +42,7 @@ serving layer folds delta ranks into snapshot ranks at lookup time.
 from __future__ import annotations
 
 import dataclasses
+import logging
 import time
 import warnings
 from typing import Any, Sequence
@@ -49,10 +50,12 @@ from typing import Any, Sequence
 import numpy as np
 
 from ..kernels.backends import BACKENDS, get_backend
-from .plex import PLEX, build_plex, freeze_arrays
+from .plex import PLEX, as_radix, build_plex, freeze_arrays, layers_unify
 
 # keep each shard's float32 rank plane well inside the 2^24 limit
 SHARD_MAX_KEYS = 1 << 23
+
+log = logging.getLogger(__name__)
 
 
 @dataclasses.dataclass
@@ -250,6 +253,11 @@ class Snapshot:
         when process start-up would dominate). Per-shard phase timings are
         aggregated on ``Snapshot.build_stats`` either way.
 
+        Shards are tuned one by one; when their layers come out apart
+        (``plex.layers_unify``), the CHT shards are re-tuned over radix
+        tables so that the snapshot always serves through one stacked
+        pipeline.
+
         Ownership: the key array is adopted and **frozen in place**
         (``writeable = False``) rather than copied — at the 200M-key scale
         this repo targets, a defensive copy would double resident memory.
@@ -270,6 +278,11 @@ class Snapshot:
         plexes = build_shard_plexes(
             keys, offsets, eps, workers=int(workers or 1), pool=pool,
             mp_context=mp_context, **build_kw)
+        if not layers_unify(plexes):
+            # shards tuned apart (mixed radix/CHT, or CHT radix widths that
+            # differ) would leave serving without its one stacked pipeline:
+            # re-tune the CHT shards over radix tables, which always unify
+            plexes = [as_radix(px, **build_kw) for px in plexes]
         shards = []
         for s, px in enumerate(plexes):
             dev = devices[s % len(devices)] if devices else None
@@ -339,6 +352,13 @@ class Snapshot:
                 [s.plex for s in self.shards], self.offsets, block=block,
                 probe=probe, cache_slots=cache_slots, host_planes=hps,
                 sharding=None)
+            if self._stacked[cfg] is None:
+                # the serving layer drops to per-shard host routing: say so
+                log.warning(
+                    "snapshot epoch %d: the layers of its %d shards do not "
+                    "unify into one stacked %s pipeline; lookups take the "
+                    "per-shard host-routed path", self.epoch, self.n_shards,
+                    backend)
             self._stacked_built = True
         self._stacked_last = self._stacked[cfg]
         return self._stacked_last

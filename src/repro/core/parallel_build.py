@@ -6,16 +6,20 @@ is embarrassingly parallel at shard granularity. This module is the fan-out
 engine behind ``Snapshot.build(..., workers=N)`` and the streamed durable
 build (``build_generation``):
 
-* **Zero-copy key passing.** The frozen key array is never pickled into the
-  workers. Three transports, picked automatically per (array, pool):
+* **Key passing.** The whole key array is never pickled into a worker.
+  Three transports, picked automatically per (array, pool):
 
   - file-backed ``np.memmap`` keys (the SOSD datasets): each worker re-maps
     the same file range read-only;
   - fork start method: the parent parks the array in a module-level table
     before the pool forks, so children inherit the pages copy-on-write;
-  - spawn start method: one copy into a tmpfs-backed scratch file
-    (``/dev/shm`` when present) each worker memmaps read-only — the only
-    transport that pays a single memcpy.
+  - spawn start method (the parent has already imported jax, e.g. it
+    holds the chip): each task carries its own shard's keys, pickled once
+    — one memcpy per key, and a worker holds one shard, never the whole
+    array. (Mapping one shared scratch file instead charged each worker
+    on a TPU host about 1.7 GB resident for the whole 1.6 GB array.)
+    Workers import only the jax-free ``repro.core``, so no worker ever
+    initialises a jax backend or reaches for the parent's chip.
 
 * **Shard-order streaming.** ``iter_built_shards`` yields ``(s, PLEX)`` in
   shard order as soon as each shard (and all its predecessors) completes,
@@ -40,10 +44,8 @@ from __future__ import annotations
 import concurrent.futures
 import itertools
 import multiprocessing
-import os
 import pathlib
 import sys
-import tempfile
 import threading
 import time
 from typing import Iterator
@@ -73,7 +75,8 @@ _token_lock = threading.Lock()
 def _keys_descriptor(keys: np.ndarray, start_method: str):
     """-> (picklable transport descriptor, cleanup callable). The
     descriptor tells ``_pool_init`` how to materialise the key array in a
-    worker without pickling it."""
+    worker; ``None`` means no shared array: each task carries its own
+    shard's keys."""
     if isinstance(keys, np.memmap) and getattr(keys, "filename", None):
         return (("mmap", str(keys.filename), int(keys.offset),
                  str(keys.dtype.str), int(keys.size)), lambda: None)
@@ -82,33 +85,15 @@ def _keys_descriptor(keys: np.ndarray, start_method: str):
             token = next(_token_counter)
         _INHERITED[token] = keys
         return ("inherit", token), lambda: _INHERITED.pop(token, None)
-    # spawn/forkserver: one shared copy through a tmpfs-backed scratch file
-    # the workers memmap read-only (/dev/shm makes it a RAM copy — same
-    # cost as a SharedMemory segment without its per-process resource-
-    # tracker bookkeeping). Unlinked by the parent after the build; POSIX
-    # keeps the pages alive for the workers' open maps.
-    shm_dir = "/dev/shm" if os.path.isdir("/dev/shm") else None
-    fd, path = tempfile.mkstemp(prefix="plex-build-keys-", dir=shm_dir)
-    try:
-        with os.fdopen(fd, "wb") as fh:
-            np.ascontiguousarray(keys).tofile(fh)
-    except BaseException:
-        os.unlink(path)
-        raise
-
-    def cleanup() -> None:
-        try:
-            os.unlink(path)
-        except OSError:  # pragma: no cover
-            pass
-
-    return ("mmap", path, 0, str(keys.dtype.str), int(keys.size)), cleanup
+    return None, lambda: None
 
 
 def _pool_init(desc) -> None:
     """Worker initializer: materialise the shared key array once per
     worker process (module global), whatever the transport."""
     global _WORKER_KEYS
+    if desc is None:
+        return
     kind = desc[0]
     if kind == "mmap":
         _, path, offset, dtype, n = desc
@@ -118,12 +103,16 @@ def _pool_init(desc) -> None:
         _WORKER_KEYS = _INHERITED[desc[1]]
 
 
-def _build_shard_task(s: int, lo: int, hi: int, eps: int,
-                      build_kw: dict) -> tuple[int, PLEX]:
-    """One worker task: build shard ``s`` over the process-shared key
-    array. ``keys`` is stripped before pickling the result back — the
-    parent re-attaches its own view, so only index planes cross the pipe."""
-    px = build_plex(_WORKER_KEYS[lo:hi], eps, **build_kw)
+def _build_shard_task(s: int, lo: int, hi: int, eps: int, build_kw: dict,
+                      shard_keys: np.ndarray | None = None
+                      ) -> tuple[int, PLEX]:
+    """One worker task: build shard ``s`` over ``shard_keys`` when the task
+    carries them, else over the process-shared key array. ``keys`` is
+    stripped before pickling the result back — the parent re-attaches its
+    own view, so only index planes cross the pipe."""
+    if shard_keys is None:
+        shard_keys = _WORKER_KEYS[lo:hi]
+    px = build_plex(shard_keys, eps, **build_kw)
     px.keys = None
     return s, px
 
@@ -191,7 +180,8 @@ def iter_built_shards(keys: np.ndarray, offsets: np.ndarray, eps: int, *,
             initializer=_pool_init, initargs=(desc,))
 
         def submit(s: int, lo: int, hi: int):
-            return ex.submit(_build_shard_task, s, lo, hi, eps, build_kw)
+            return ex.submit(_build_shard_task, s, lo, hi, eps, build_kw,
+                             None if desc else keys[lo:hi])
     else:
         raise ValueError(f"pool must be 'process', 'thread', or 'serial', "
                          f"got {pool!r}")

@@ -179,3 +179,34 @@ def build_plex(keys: np.ndarray, eps: int, *,
     return PLEX(spline=spline, layer=layer, tuning=tuning, keys=keys,
                 eps=eps, stats=BuildStats(spline_s=t1 - t0, tune_s=t2 - t1,
                                           layer_s=t3 - t2, total_s=t3 - t0))
+
+
+def layers_unify(plexes) -> bool:
+    """Whether one stacked pipeline can serve all ``plexes`` as shards: one
+    layer kind and, for CHT layers, one radix width (``kernels.planes``)."""
+    if all(isinstance(px.layer, RadixTable) for px in plexes):
+        return True
+    return (all(isinstance(px.layer, CHT) for px in plexes)
+            and len({px.layer.r for px in plexes}) == 1)
+
+
+def as_radix(px: PLEX, *, r_max_radix: int = 24,
+             tune_sample: int | None = None, budget_bytes: int | None = None,
+             **_cht_kw) -> PLEX:
+    """``px`` with its layer re-tuned over flat radix tables only, spline
+    kept. Takes ``build_plex``'s keywords; the CHT ones do not apply."""
+    if isinstance(px.layer, RadixTable):
+        return px
+    t0 = time.perf_counter()
+    tuning = tune(px.spline, px.keys, r_max_radix=r_max_radix,
+                  sample=tune_sample, budget_bytes=budget_bytes,
+                  allow_cht=False)
+    t1 = time.perf_counter()
+    layer = build_radix_table(px.spline.keys, tuning.r)
+    t2 = time.perf_counter()
+    st = px.stats
+    return dataclasses.replace(
+        px, layer=layer, tuning=tuning,
+        stats=BuildStats(spline_s=st.spline_s, tune_s=st.tune_s + t1 - t0,
+                         layer_s=st.layer_s + t2 - t1,
+                         total_s=st.total_s + t2 - t0))

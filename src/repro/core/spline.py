@@ -45,7 +45,7 @@ def _unique_first(keys: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
     mask = np.empty(keys.size, dtype=bool)
     mask[0] = True
     np.not_equal(keys[1:], keys[:-1], out=mask[1:])
-    pos = np.nonzero(mask)[0].astype(np.int64)
+    pos = np.nonzero(mask)[0].astype(np.int64, copy=False)
     return keys[mask], pos
 
 
@@ -54,8 +54,6 @@ def _greedy_indices(ukeys: np.ndarray, upos: np.ndarray, eps: float) -> np.ndarr
     n = ukeys.size
     if n <= 2:
         return np.arange(n, dtype=np.int64)
-    kx = ukeys.astype(_LD)
-    ky = upos.astype(_LD)
     eps_ld = _LD(eps)
 
     out = [0]
@@ -66,8 +64,9 @@ def _greedy_indices(ukeys: np.ndarray, upos: np.ndarray, eps: float) -> np.ndarr
     chunk = 64
     while i0 < n:
         j1 = min(i0 + chunk, n)
-        dx = kx[i0:j1] - kx[b]
-        dy = ky[i0:j1] - ky[b]
+        # long double per chunk: a whole-shard copy would cost 32 B per key
+        dx = ukeys[i0:j1].astype(_LD) - _LD(ukeys[b])
+        dy = upos[i0:j1].astype(_LD) - _LD(upos[b])
         s = dy / dx
         s_hi = (dy + eps_ld) / dx
         s_lo = (dy - eps_ld) / dx
@@ -112,6 +111,20 @@ def _interp_f64(sk: np.ndarray, sp: np.ndarray, q: np.ndarray) -> np.ndarray:
     qf = q.astype(np.float64)
     t = np.where(x1 > x0, (qf - x0) / np.maximum(x1 - x0, 1.0), 0.0)
     return y0 + t * (y1 - y0)
+
+
+def _violations(sk: np.ndarray, sp: np.ndarray, ukeys: np.ndarray,
+                upos: np.ndarray, eps: int, chunk: int = 1 << 20
+                ) -> np.ndarray:
+    """Indices of the unique keys whose float64 prediction misses their
+    first-occurrence rank by more than ``eps``. Chunked, so the float64
+    temporaries stay O(chunk) instead of ~80 B per key of the shard."""
+    out = [np.zeros(0, dtype=np.int64)]
+    for i in range(0, ukeys.size, chunk):
+        pred = _interp_f64(sk, sp, ukeys[i:i + chunk])
+        bad = np.abs(pred - upos[i:i + chunk].astype(np.float64)) > eps
+        out.append(np.nonzero(bad)[0] + i)
+    return np.concatenate(out)
 
 
 @dataclasses.dataclass
@@ -163,11 +176,9 @@ def build_spline(keys: np.ndarray, eps: int) -> Spline:
 
     # Verification/repair: enforce the paper's bound under float64 arithmetic.
     for _ in range(8):
-        pred = _interp_f64(sk, sp, ukeys)
-        bad = np.abs(pred - upos.astype(np.float64)) > eps
-        if not bad.any():
+        extra = _violations(sk, sp, ukeys, upos, eps)
+        if not extra.size:
             break
-        extra = np.nonzero(bad)[0]
         take = np.union1d(np.searchsorted(ukeys, sk), extra)
         sk, sp = ukeys[take], upos[take]
     else:  # pragma: no cover - repair always converges (every point selected)

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .pairs import pair_lt
+from .plex_segment_lookup import default_interpret
 
 DEFAULT_BLOCK = 512
 
@@ -34,8 +35,10 @@ def _body(qhi_ref, qlo_ref, whi_ref, wlo_ref, base_ref, out_ref):
 
 
 def bounded_search(qhi, qlo, win_hi, win_lo, base, *, block=DEFAULT_BLOCK,
-                   interpret=True):
+                   interpret=None):
     """Lower-bound index per query given its [W]-wide sorted data window."""
+    if interpret is None:
+        interpret = default_interpret()
     b, w = win_hi.shape
     assert b % block == 0 and w % 128 == 0
     grid = (b // block,)

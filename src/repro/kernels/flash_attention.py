@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .plex_segment_lookup import default_interpret
+
 NEG_INF = -1e30
 
 
@@ -62,8 +64,11 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *, scale, causal,
 def flash_attention_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                         causal: bool = True, scale: float | None = None,
                         block_q: int = 256, block_k: int = 256,
-                        interpret: bool = True) -> jnp.ndarray:
-    """q [B,Sq,H,D], k/v [B,Skv,KVH,D] -> [B,Sq,H,D] (forward only)."""
+                        interpret: bool | None = None) -> jnp.ndarray:
+    """q [B,Sq,H,D], k/v [B,Skv,KVH,D] -> [B,Sq,H,D] (forward only).
+    ``interpret`` defaults by platform (``default_interpret``)."""
+    if interpret is None:
+        interpret = default_interpret()
     b, sq, h, d = q.shape
     _, skv, kvh, _ = k.shape
     g = h // kvh

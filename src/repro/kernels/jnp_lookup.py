@@ -44,7 +44,8 @@ import numpy as np
 from ..core.plex import PLEX
 from ..obs.metrics import METRICS
 from .pairs import extract_bits, pair_le, split_u64
-from .planes import (DeltaPlanes, PlexPlanes, StackedPlanes, build_planes,
+from .planes import (PLANE_ARRAYS, STACKED_ARRAYS, DeltaPlanes, PlexPlanes,
+                     StackedPlanes, bind_planes, build_planes,
                      build_stacked_planes, finalize_indices, pad_queries)
 from .plex_segment_lookup import (DEFAULT_BLOCK, cht_window_base,
                                   probe_lower_bound, radix_window_base,
@@ -105,7 +106,9 @@ class JnpPlex:
                 dhi=put(pp.dhi), dlo=put(pp.dlo),
                 layer_arrays={k: put(v) for k, v in pp.layer_arrays.items()})
         jp = cls(planes=pp, block=block, probe=probe)
-        jp._fn = jax.jit(functools.partial(_jnp_pipeline, pp, probe))
+        jp._fn = bind_planes(
+            lambda p, qhi, qlo: _jnp_pipeline(p, probe, qhi, qlo), pp,
+            PLANE_ARRAYS)
         return jp
 
     def lookup_planes(self, qhi, qlo):
@@ -198,7 +201,7 @@ def _probe_bucket(dist):
     return jnp.clip(b, 0, N_PROBE_BUCKETS - 1)
 
 
-def _stacked_counted(aux, n_shards: int, cap: int, qhi, qlo, n_valid,
+def _stacked_counted(aux, n_shards: int, cap: int, sp, qhi, qlo, n_valid,
                      counters, dkhi=None, dklo=None, dcum=None):
     """The counted dispatch: stacked (optionally merged) pipeline plus the
     device-resident telemetry counter plane.
@@ -216,7 +219,7 @@ def _stacked_counted(aux, n_shards: int, cap: int, qhi, qlo, n_valid,
     (``cap == 0`` is the read-only epoch), which adjusts results only —
     routing and probing are snapshot-side work either way.
     """
-    res, sid, dist = aux(qhi, qlo)
+    res, sid, dist = aux(sp, qhi, qlo)
     inc = (jax.lax.iota(jnp.int32, qhi.shape[0])
            < n_valid).astype(jnp.uint32)
     counters = counters.at[sid].add(inc)
@@ -244,14 +247,14 @@ def delta_rank_adjust(qhi, qlo, dkhi, dklo, dcum, *, cap: int):
     return jnp.take(dcum, cnt)
 
 
-def _stacked_merged(pipeline, cap: int, qhi, qlo, dkhi, dklo, dcum):
+def _stacked_merged(pipeline, cap: int, sp, qhi, qlo, dkhi, dklo, dcum):
     """Snapshot pipeline + delta fold: global *merged* first-occurrence
     indices equal to searchsorted over the logical (snapshot - tombstones +
     inserts) key array, in one dispatch. ``pipeline`` is the backend's
-    snapshot-rank function ``(qhi, qlo) -> int32 [B]`` (the jnp stacked
+    snapshot-rank function ``(planes, qhi, qlo) -> int32 [B]`` (the jnp stacked
     pipeline here; the Pallas backend fuses the fold into its kernel and
     does not use this composition)."""
-    out = pipeline(qhi, qlo)
+    out = pipeline(sp, qhi, qlo)
     return out + delta_rank_adjust(qhi, qlo, dkhi, dklo, dcum, cap=cap)
 
 
@@ -266,15 +269,15 @@ def _cache_slot(qhi, qlo, n_slots: int):
 _CACHE_EMPTY = 0xFFFFFFFF   # sentinel value row; real indices are < 2^31
 
 
-def _stacked_cached(pipeline, cap: int, qhi, qlo,
+def _stacked_cached(pipeline, cap: int, sp, qhi, qlo,
                     n_valid, cache, dkhi=None, dklo=None, dcum=None):
     """Stacked (optionally merged) pipeline + device hot-key result cache.
 
-    ``pipeline`` is the backend's snapshot-rank function ``(qhi, qlo) ->
-    int32 [B]`` — the jnp stacked pipeline or the fused Pallas kernel; the
-    cache resolution, write-through, and delta fold below are backend-
-    independent management that wraps whichever pipeline misses run
-    through.
+    ``pipeline`` is the backend's snapshot-rank function ``(planes, qhi,
+    qlo) -> int32 [B]`` — the jnp stacked pipeline or the fused Pallas
+    kernel; the cache resolution, write-through, and delta fold below are
+    backend-independent management that wraps whichever pipeline misses
+    run through.
 
     The cache is explicit state threaded through every micro-batch: one
     uint32 [3, n_slots] array (rows: key hi, key lo, cached *snapshot*
@@ -312,7 +315,7 @@ def _stacked_cached(pipeline, cap: int, qhi, qlo,
         return cval.astype(jnp.int32)
 
     def slow(_):
-        return pipeline(qhi, qlo)
+        return pipeline(sp, qhi, qlo)
 
     snap = jax.lax.cond(full_hit, fast, slow, None)
     snap = jnp.where(hit, cval.astype(jnp.int32), snap)
@@ -403,43 +406,50 @@ class StackedJnpPlex:
         return st
 
     # -- backend builder hooks ----------------------------------------------
+    def _bind(self, body):
+        """jit ``body(planes, *args)`` over this impl's planes, passed as
+        program arguments (``planes.bind_planes``)."""
+        return bind_planes(body, self.planes, STACKED_ARRAYS)
+
     def _snapshot_fn(self):
-        """The snapshot-rank pipeline ``(qhi, qlo) -> int32 [B]`` (untraced;
-        the cached wrapper composes around it). Subclasses override."""
-        return functools.partial(_stacked_pipeline, self.planes, self.probe)
+        """The snapshot-rank pipeline ``(planes, qhi, qlo) -> int32 [B]``
+        (untraced; the merged and cached wrappers compose around it).
+        Subclasses override."""
+        probe = self.probe
+        return lambda sp, qhi, qlo: _stacked_pipeline(sp, probe, qhi, qlo)
 
     def _build_fn(self, cap: int):
         """jit'd full dispatch at delta capacity ``cap`` (0 = delta-free:
         ``(qhi, qlo)``; else merged: ``(qhi, qlo, dkhi, dklo, dcum)``).
         Subclasses override to swap the compute."""
         if cap == 0:
-            return jax.jit(self._snapshot_fn())
-        return jax.jit(functools.partial(_stacked_merged,
-                                         self._snapshot_fn(), cap))
+            return self._bind(self._snapshot_fn())
+        return self._bind(functools.partial(_stacked_merged,
+                                            self._snapshot_fn(), cap))
 
     def _build_cached_fn(self, cap: int):
         """jit'd hot-key-cached dispatch at delta capacity ``cap``. The
         cache wrapper itself is backend-independent (``_stacked_cached``);
         it wraps whatever ``_snapshot_fn`` the backend supplies, so cached
         misses still run the backend's own pipeline."""
-        return jax.jit(functools.partial(_stacked_cached,
-                                         self._snapshot_fn(), cap))
+        return self._bind(functools.partial(_stacked_cached,
+                                            self._snapshot_fn(), cap))
 
     def _aux_fn(self):
-        """The instrumented pipeline ``(qhi, qlo) -> (res, sid, dist)``
-        feeding the counted dispatch. The default is the jnp expression of
-        the stacked pipeline over this impl's planes — every stacked
-        backend shares the same planes and the same pipeline math (the
-        Pallas kernel body *is* this pipeline), so the counted results are
-        bit-identical to the backend's own by construction."""
-        return functools.partial(_stacked_pipeline_aux, self.planes,
-                                 self.probe)
+        """The instrumented pipeline ``(planes, qhi, qlo) -> (res, sid,
+        dist)`` feeding the counted dispatch. The default is the jnp
+        expression of the stacked pipeline — every stacked backend shares
+        the same planes and the same pipeline math (the Pallas kernel body
+        *is* this pipeline), so the counted results are bit-identical to
+        the backend's own by construction."""
+        probe = self.probe
+        return lambda sp, qhi, qlo: _stacked_pipeline_aux(sp, probe, qhi, qlo)
 
     def _build_counted_fn(self, cap: int):
         """jit'd counted dispatch at delta capacity ``cap`` (observability
         armed): full pipeline + the telemetry counter-plane scatter."""
-        return jax.jit(functools.partial(_stacked_counted, self._aux_fn(),
-                                         self.planes.n_shards, cap))
+        return self._bind(functools.partial(
+            _stacked_counted, self._aux_fn(), self.planes.n_shards, cap))
 
     def _counted_fn(self, cap: int):
         fn = self._counted_fns.get(cap)

@@ -14,21 +14,19 @@ reads never wrap.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import warnings
 from typing import Any
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..core.plex import PLEX
 from .bounded_search import bounded_search
 from .pairs import extract_bits, split_u64
-from .planes import (COUNT_MODE_MAX, build_planes, finalize_indices,
-                     pad_queries)
+from .planes import (COUNT_MODE_MAX, PLANE_ARRAYS, bind_planes, build_planes,
+                     finalize_indices, pad_queries)
 from .plex_segment_lookup import (DEFAULT_BLOCK, cht_segment_lookup,
-                                  radix_segment_lookup)
+                                  default_interpret, radix_segment_lookup)
 
 __all__ = ["COUNT_MODE_MAX", "DevicePlex"]
 
@@ -58,14 +56,17 @@ class DevicePlex:
 
     @classmethod
     def from_plex(cls, px: PLEX, *, block: int = DEFAULT_BLOCK,
-                  interpret: bool = True) -> "DevicePlex":
+                  interpret: bool | None = None) -> "DevicePlex":
+        """``interpret`` defaults by platform (``default_interpret``)."""
+        if interpret is None:
+            interpret = default_interpret()
         pp = build_planes(px)
         dp = cls(skhi=pp.skhi, sklo=pp.sklo, spos=pp.spos, dhi=pp.dhi,
                  dlo=pp.dlo, n_data=pp.n_data, n_real=pp.n_real, kind=pp.kind,
                  layer_arrays=pp.layer_arrays, static=pp.static,
                  eps_eff=pp.eps_eff, window=pp.window, block=block,
                  interpret=interpret, _px=px)
-        dp._fn = jax.jit(functools.partial(_lookup_pipeline, dp))
+        dp._fn = bind_planes(_lookup_pipeline, dp, PLANE_ARRAYS)
         return dp
 
     def lookup_planes(self, qhi, qlo):

@@ -33,8 +33,10 @@ depends on it:
   (radix ``shift``/``min_key``/table extent, CHT ``delta``) become [S]
   parameter planes gathered per query. Shards whose layers cannot be
   unified (mixed radix/CHT kinds, or CHT shards with different radix
-  widths) are rejected — ``build_stacked_planes`` returns ``None`` and the
-  serving layer falls back to per-shard dispatch.
+  widths; ``core.plex.layers_unify``) are rejected —
+  ``build_stacked_planes`` returns ``None`` and the serving layer falls
+  back to per-shard dispatch. ``Snapshot.build`` never produces such a
+  shard set: it re-tunes the CHT shards of one over radix tables.
 """
 from __future__ import annotations
 
@@ -47,7 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.cht import CHT
-from ..core.plex import PLEX
+from ..core.plex import PLEX, layers_unify
 from ..core.radix_table import RadixTable
 from .pairs import split_u64
 
@@ -58,6 +60,36 @@ _U64_MAX = np.iinfo(np.uint64).max
 
 def round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
+
+
+@dataclasses.dataclass(frozen=True)
+class BoundPlanes:
+    """A jit'd ``body(planes, *args)`` bound to one set of device planes.
+
+    The plane arrays are *arguments* of the compiled program, never
+    closed-over constants: a closure would bake every plane into the
+    program (1.6 GB of constants for a 200M-key index) and upload it again
+    with the executable. As arguments they stay where they were placed,
+    and ``lower`` compiles from shapes alone when the bound arrays are
+    ``jax.ShapeDtypeStruct`` (the ahead-of-time chip compile check)."""
+    jitted: Any
+    arrays: dict
+
+    def __call__(self, *args):
+        return self.jitted(self.arrays, *args)
+
+    def lower(self, *args):
+        return self.jitted.lower(self.arrays, *args)
+
+
+def bind_planes(body, planes, fields: Sequence[str]) -> BoundPlanes:
+    """jit ``body(planes, *args)`` with ``planes``' array ``fields`` passed
+    as arguments (see ``BoundPlanes``); every other field of the planes
+    dataclass stays a trace-time static."""
+    def traced(arrays, *args):
+        return body(dataclasses.replace(planes, **arrays), *args)
+    return BoundPlanes(jax.jit(traced),
+                       {f: getattr(planes, f) for f in fields})
 
 
 def pad_queries(q: np.ndarray, block: int) -> tuple[np.ndarray, int]:
@@ -97,6 +129,10 @@ class PlexPlanes:
     static: dict[str, Any]
     eps_eff: int
     window: int
+
+
+# the device-array fields of PlexPlanes / StackedPlanes (``bind_planes``)
+PLANE_ARRAYS = ("skhi", "sklo", "spos", "dhi", "dlo", "layer_arrays")
 
 
 @dataclasses.dataclass
@@ -292,6 +328,10 @@ class StackedPlanes:
     window: int               # max over shards
 
 
+STACKED_ARRAYS = PLANE_ARRAYS + ("n_spline", "n_real", "row_off",
+                                 "min_hi", "min_lo")
+
+
 def build_stacked_planes(plexes: Sequence[PLEX], row_off: np.ndarray,
                          host_planes: Sequence[_HostPlanes] | None = None,
                          sharding: Any = None) -> StackedPlanes | None:
@@ -319,14 +359,11 @@ def build_stacked_planes(plexes: Sequence[PLEX], row_off: np.ndarray,
     """
     put = (jnp.asarray if sharding is None
            else functools.partial(jax.device_put, device=sharding))
+    if not layers_unify(plexes):
+        return None
     hps = (list(host_planes) if host_planes is not None
            else [_host_planes(px) for px in plexes])
-    kinds = {hp.kind for hp in hps}
-    if len(kinds) != 1:
-        return None
-    kind = kinds.pop()
-    if kind == "cht" and len({hp.static["r"] for hp in hps}) != 1:
-        return None
+    kind = hps[0].kind
     n_real_total = int(row_off[-1]) + hps[-1].n_real
     if n_real_total >= (1 << 31):
         return None

@@ -36,6 +36,12 @@ from .pairs import pair_le, pair_lt, pair_shr, pair_shr_dyn, pair_sub, \
 DEFAULT_BLOCK = 512
 
 
+def default_interpret() -> bool:
+    """Pallas interpret mode by platform: compiled on a TPU, where nothing
+    runs in the interpreter; interpreted elsewhere (the CPU parity mode)."""
+    return jax.default_backend() != "tpu"
+
+
 def _predecessor_count(qhi, qlo, skhi, sklo, lo, hi):
     """Masked popcount predecessor search: largest i in [lo, hi] with
     sk[i] <= q (assumes sk[lo] <= q), window width static = max over batch."""
@@ -301,8 +307,10 @@ def _cht_body(qhi_ref, qlo_ref, bins_ref, cells_ref, skhi_ref, sklo_ref,
 
 def radix_segment_lookup(qhi, qlo, table, skhi, sklo, spos, *, shift, r,
                          min_hi, min_lo, max_win, eps_eff, n_data, window,
-                         mode="count", block=DEFAULT_BLOCK, interpret=True):
+                         mode="count", block=DEFAULT_BLOCK, interpret=None):
     """Window bases [B] for a batch of queries through a radix-table layer."""
+    if interpret is None:
+        interpret = default_interpret()
     b = qhi.shape[0]
     assert b % block == 0, "ops.py pads the batch"
     n_spline = skhi.shape[0]
@@ -326,9 +334,11 @@ def radix_segment_lookup(qhi, qlo, table, skhi, sklo, spos, *, shift, r,
 
 def cht_segment_lookup(qhi, qlo, bins, cells, skhi, sklo, spos, *, r, levels,
                        delta, eps_eff, n_data, window, mode="count",
-                       block=DEFAULT_BLOCK, interpret=True):
+                       block=DEFAULT_BLOCK, interpret=None):
     """Window bases [B] through a CHT layer. ``bins`` is int32 [levels, B]
     (per-level radix digits, precomputed vectorised outside the kernel)."""
+    if interpret is None:
+        interpret = default_interpret()
     b = qhi.shape[0]
     assert b % block == 0
     n_spline = skhi.shape[0]

@@ -48,7 +48,7 @@ from jax.experimental import pallas as pl
 
 from .jnp_lookup import StackedJnpPlex, _stacked_pipeline, delta_rank_adjust
 from .planes import StackedPlanes
-from .plex_segment_lookup import DEFAULT_BLOCK
+from .plex_segment_lookup import DEFAULT_BLOCK, default_interpret
 
 # canonical positional order of the StackedPlanes array fields as kernel
 # inputs (the layer_arrays dict follows, in sorted-key order)
@@ -84,11 +84,15 @@ def _kernel_body(sp: StackedPlanes, layer_keys, probe: str, cap: int, *refs):
 
 def stacked_pallas_lookup(sp: StackedPlanes, probe: str, cap: int,
                           qhi, qlo, dkhi=None, dklo=None, dcum=None, *,
-                          block: int = DEFAULT_BLOCK, interpret: bool = True):
+                          block: int = DEFAULT_BLOCK,
+                          interpret: bool | None = None):
     """Global (optionally merged) int32 indices for a block-multiple query
     batch, one ``pallas_call`` dispatch covering the entire pipeline.
     ``cap > 0`` appends the ``DeltaPlanes`` arrays and folds the delta
-    rank adjustment inside the same kernel."""
+    rank adjustment inside the same kernel. ``interpret`` defaults by
+    platform (``default_interpret``)."""
+    if interpret is None:
+        interpret = default_interpret()
     b = qhi.shape[0]
     assert b % block == 0, "callers pad the batch to a block multiple"
     plane_arrays, layer_keys = _plane_inputs(sp)
@@ -122,7 +126,7 @@ class StackedPallasPlex(StackedJnpPlex):
     kernel remains the obs-off serving path; only an *observed* run pays
     the jnp dispatch."""
 
-    interpret: bool = True
+    interpret: bool = dataclasses.field(default_factory=default_interpret)
 
     @classmethod
     def from_plexes(cls, plexes, row_off, *, block: int = DEFAULT_BLOCK,
@@ -134,21 +138,24 @@ class StackedPallasPlex(StackedJnpPlex):
         defaults by platform: compiled on TPU, interpreter elsewhere (the
         CPU parity/CI mode)."""
         if interpret is None:
-            interpret = jax.default_backend() != "tpu"
+            interpret = default_interpret()
         return super().from_plexes(
             plexes, row_off, block=block, probe=probe,
             cache_slots=cache_slots, host_planes=host_planes,
             sharding=sharding, interpret=interpret)
 
+    def _kernel(self, cap: int):
+        """The fused kernel ``(planes, qhi, qlo[, dkhi, dklo, dcum])`` at
+        delta capacity ``cap`` (untraced)."""
+        probe, block, interpret = self.probe, self.block, self.interpret
+        return lambda sp, *args: stacked_pallas_lookup(
+            sp, probe, cap, *args, block=block, interpret=interpret)
+
     def _snapshot_fn(self):
-        return functools.partial(stacked_pallas_lookup, self.planes,
-                                 self.probe, 0, block=self.block,
-                                 interpret=self.interpret)
+        return self._kernel(0)
 
     def _build_fn(self, cap: int):
         """Delta-free and merged dispatches are both the one fused kernel —
         ``cap`` bakes the delta fold (and its fixed-trip bisect) into the
         kernel body, so merged epochs stay at one ``pallas_call`` too."""
-        return jax.jit(functools.partial(
-            stacked_pallas_lookup, self.planes, self.probe, cap,
-            block=self.block, interpret=self.interpret))
+        return self._bind(self._kernel(cap))

@@ -74,6 +74,7 @@ __all__ = [
     "POINT_MERGE_WORKER", "POINT_PARTITION_LOAD", "POINT_SNAPSHOT_MAP",
     "POINT_WAL_APPEND", "POINT_WAL_FSYNC",
     "always", "fail_n", "fail_once", "fire", "injected", "intermittent",
+    "is_injected",
 ]
 
 POINT_BACKEND_FACTORY = "backend.factory"
@@ -98,7 +99,15 @@ INJECTION_POINTS = (
 class InjectedFault(RuntimeError):
     """The default exception an armed scenario raises. Deliberately a
     plain ``RuntimeError`` subclass: the production handlers must treat it
-    exactly like a real dispatch/IO failure, never special-case it."""
+    exactly like a real dispatch/IO failure. The one exception is
+    ``PlexService.warmup``, which stays best-effort under a drill but
+    raises a real failure of the configured backend (``is_injected``)."""
+
+
+def is_injected(e: BaseException) -> bool:
+    """Whether ``e`` was raised by an armed scenario (whatever its
+    ``exc`` type), as opposed to a real failure."""
+    return getattr(e, "injected_at", None) is not None
 
 
 @dataclasses.dataclass
@@ -229,7 +238,9 @@ class FaultRegistry:
                 return
         detail = f" ({', '.join(f'{k}={v!r}' for k, v in ctx.items())})" \
             if ctx else ""
-        raise exc(f"injected fault at {point}{detail}")
+        err = exc(f"injected fault at {point}{detail}")
+        err.injected_at = point
+        raise err
 
     @contextlib.contextmanager
     def injected(self, point: str, scenario: Scenario) -> Iterator[Scenario]:

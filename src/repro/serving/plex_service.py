@@ -167,7 +167,8 @@ from ..resilience.errors import (BackendUnavailableError, MergeFailedError,
                                  NoServableGenerationError,
                                  PartitionLoadError, QueueFullError)
 from ..resilience.faults import (FAULTS, POINT_BACKEND_DISPATCH,
-                                 POINT_MERGE_BUILD, POINT_MERGE_WORKER, fire)
+                                 POINT_MERGE_BUILD, POINT_MERGE_WORKER, fire,
+                                 is_injected)
 from .delta import DELTA_CAP_MIN, DeltaBuffer, next_pow2
 
 __all__ = ["DEFAULT_BLOCK", "DEFAULT_MERGE_THRESHOLD",
@@ -2067,10 +2068,16 @@ class PlexService:
         take in this epoch: the delta-free pipeline and the merged pipeline
         at the standing delta capacity — so neither the first update nor a
         queue flush on the deadline timer thread ever hits a cold
-        compile. Best-effort: a backend whose warm dispatch fails is left
-        cold (noted in ``health()``'s error journal) — the serving chain
-        handles the failure properly at lookup time, so warmup must never
-        crash what degraded serving would survive."""
+        compile.
+
+        A real failure here — the compiler refusing the backend's program,
+        a lowering error, a device error — is a configuration error and
+        propagates with its own message: a backend that cannot compile on
+        this platform must not be served through the fallback chain in
+        silence. Only an injected fault (``resilience.faults`` drills) is
+        best-effort: the backend is left cold, the fault noted in
+        ``health()``'s error journal, and the chain covers it at lookup
+        time."""
         backend = backend or self.default_backend
         try:
             if get_backend(backend).stacked_factory is not None:
@@ -2087,6 +2094,8 @@ class PlexService:
             for shard in self.shards:
                 shard.warmup(backend)
         except Exception as e:
+            if not is_injected(e):
+                raise
             self._note_error(e)
             log.warning("warmup: backend %r failed (%s); left cold — the "
                         "fallback chain covers it at lookup time", backend, e)

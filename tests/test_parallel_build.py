@@ -4,10 +4,11 @@ The tentpole contract (ISSUE 8): ``Snapshot.build(..., workers=N)`` and the
 streamed durable ``build_generation`` produce results **bit-identical** to
 the serial build — same shard planes, same tuning decisions, same persisted
 snapshot bytes (modulo the wall-clock ``build_s`` header field), same
-lookup results — with the key array shared into the workers by
-memmap/fork/tmpfs transport, never pickled."""
+lookup results — with the key array shared into the workers by memmap or
+fork, or shipped one shard per task under spawn, never whole."""
 from __future__ import annotations
 
+import os
 import pathlib
 
 import numpy as np
@@ -202,3 +203,40 @@ def test_invalid_pool_rejected():
     offsets = shard_offsets(keys, 2)
     with pytest.raises(ValueError, match="pool"):
         build_shard_plexes(keys, offsets, 64, workers=2, pool="fiber")
+
+
+def test_build_worker_imports_stay_off_jax():
+    """A process that holds the chip builds through spawned workers, which
+    import only what unpickling their task needs. None of it may import
+    jax: a worker that initialised a backend would reach for the parent's
+    chip and fail or hang."""
+    import subprocess
+    import sys
+
+    import repro.core
+    src = pathlib.Path(repro.core.__file__).resolve().parents[2]
+    code = ("import sys, repro.core.parallel_build; "
+            "sys.exit(int('jax' in sys.modules))")
+    r = subprocess.run([sys.executable, "-c", code],
+                       env={**os.environ, "PYTHONPATH": str(src)},
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stderr
+
+
+def test_spawn_transport_ships_shard_slices(tmp_path, monkeypatch):
+    """Spawned workers share no key array: each task carries its own
+    shard's keys, nothing is written outside the process, and the build
+    is bit-identical to the serial one."""
+    import tempfile
+
+    from repro.core import parallel_build
+    monkeypatch.setenv("TMPDIR", str(tmp_path))
+    monkeypatch.setattr(tempfile, "tempdir", None)   # re-read $TMPDIR
+    keys = _keys(50_000)
+    desc, _ = parallel_build._keys_descriptor(keys, "spawn")
+    assert desc is None
+    par = Snapshot.build(keys.copy(), 64, n_shards=3, workers=2,
+                         mp_context="spawn")
+    assert not list(tmp_path.iterdir())
+    assert_snapshots_identical(Snapshot.build(keys.copy(), 64, n_shards=3),
+                               par)

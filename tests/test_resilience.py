@@ -35,7 +35,7 @@ from repro.resilience.faults import (POINT_BACKEND_DISPATCH,
                                      POINT_MANIFEST_COMMIT,
                                      POINT_MERGE_BUILD, POINT_PARTITION_LOAD,
                                      POINT_SNAPSHOT_MAP, POINT_WAL_APPEND,
-                                     POINT_WAL_FSYNC)
+                                     POINT_WAL_FSYNC, is_injected)
 from repro.serving import PlexService
 from repro.serving.plex_service import QUARANTINE_DIR
 
@@ -129,8 +129,10 @@ def test_registry_intermittent_is_seeded():
 def test_registry_custom_exception_type():
     reg = FaultRegistry()
     reg.inject("p", fail_once(exc=OSError))
-    with pytest.raises(OSError):
+    with pytest.raises(OSError) as ei:
         reg.fire("p")
+    assert is_injected(ei.value)                 # whatever the exc type
+    assert not is_injected(OSError("a real one"))
 
 
 # --------------------------------------------------------- circuit breaker ----
@@ -243,6 +245,37 @@ def test_factory_fault_falls_back_then_retries(rng):
         assert np.array_equal(svc.lookup(q), exp)    # served via fallback
     assert svc.stats.fallback_lookups == 1
     assert np.array_equal(svc.lookup(q), exp)        # factory retried, jnp
+    assert svc.stats.fallback_lookups == 1
+
+
+def test_warmup_raises_default_backend_compile_error(rng, monkeypatch):
+    """A backend the platform's compiler refuses is a configuration error:
+    warmup raises the compiler's own error instead of leaving the backend
+    cold for the fallback chain to serve around in silence. The test
+    steers the platform choice to "tpu", so the Pallas wrappers compile
+    instead of interpreting — which the CPU backend refuses."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    keys = sorted_u64(rng, 20_000)
+    svc = PlexService(keys.copy(), eps=32, n_shards=2, block=BLOCK,
+                      backend="pallas")
+    assert svc.stacked_impl().interpret is False
+    with pytest.raises(ValueError, match="interpret") as ei:
+        svc.warmup()
+    assert not is_injected(ei.value)
+    assert svc.stats.fallback_lookups == 0
+
+
+def test_warmup_injected_fault_stays_best_effort(rng):
+    """A drill is not a configuration error: an injected dispatch fault
+    leaves warmup best-effort (noted, not raised), and lookups still fall
+    back along the chain to exact answers."""
+    svc, keys = _service(rng, backend="pallas")
+    q, exp = _queries(rng, keys)
+    with FAULTS.injected(POINT_BACKEND_DISPATCH, always(backend="pallas")):
+        svc.warmup()
+        assert any("injected fault" in e
+                   for e in svc.health()["last_errors"])
+        assert np.array_equal(svc.lookup(q), exp)
     assert svc.stats.fallback_lookups == 1
 
 

@@ -292,3 +292,13 @@ def test_roofline_bytes_model_sane(rng):
         by[probe] = bytes_per_lookup(st.planes, st.probe)
         assert by[probe] > 0
     assert by["bisect"] < by["count"]
+
+
+def test_roofline_peak_keyed_by_device_kind():
+    """The peak is the published figure of the chip the run is on; a
+    device the table does not know, the CPU included, is an error."""
+    from benchmarks.roofline import peak_gbps
+    assert peak_gbps("TPU v5 lite") == 819.0
+    for kind in ("cpu", "TPU v4"):
+        with pytest.raises(ValueError, match="no published peak"):
+            peak_gbps(kind)

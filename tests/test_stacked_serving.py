@@ -92,6 +92,33 @@ def test_stacked_unification_gates(rng):
     assert build_stacked_planes(plexes, offs) is not None
 
 
+def test_snapshot_build_unifies_shard_layers(rng, monkeypatch):
+    """Shards tuned apart (one CHT among radix tables, as on the 200M-key
+    amzn stand-in) are re-tuned over radix tables, so the snapshot serves
+    through the one stacked pipeline, exactly."""
+    from repro.core import Snapshot, parallel_build
+    keys = sorted_u64(rng, 30_000)
+    built = []
+
+    def tuned_apart(k, eps, **kw):
+        px = build_plex(k, eps, **kw)
+        built.append(px)
+        return _force_cht(px, r=4, delta=16) if len(built) == 2 else px
+    monkeypatch.setattr(parallel_build, "build_plex", tuned_apart)
+    snap = Snapshot.build(keys.copy(), 32, n_shards=3)
+    assert [type(s.plex.layer).__name__ for s in snap.shards] == \
+        ["RadixTable"] * 3
+    assert [s.plex.tuning.kind for s in snap.shards] == ["radix"] * 3
+    assert np.array_equal(snap.shards[1].plex.spline.keys,
+                          built[1].spline.keys)          # spline kept
+    st = snap.stacked_impl("jnp", block=512)
+    assert st is not None and st.planes.kind == "radix"
+    q = np.concatenate([keys[rng.integers(0, keys.size, 2_000)],
+                        rng.integers(keys[0], keys[-1], 500,
+                                     dtype=np.uint64)])
+    assert np.array_equal(st.lookup(q), np.searchsorted(keys, q, "left"))
+
+
 def test_service_falls_back_when_not_unifiable(rng, monkeypatch):
     keys = sorted_u64(rng, 30_000)
     svc = PlexService(keys, eps=16, n_shards=3, block=512)
