@@ -217,6 +217,9 @@ class Snapshot:
         self.shards = tuple(shards)
         self.shard_min = keys[offsets].copy()
         self.build_s = float(build_s)
+        # wall seconds of the build's phases (build.pool, build.shards,
+        # build.assemble); empty for a snapshot that was not built here
+        self.build_phases: dict[str, float] = {}
         self.epoch = int(epoch)
         freeze_arrays(self.keys, self.offsets, self.shard_min)
         for s in self.shards:
@@ -273,29 +276,36 @@ class Snapshot:
         if n_shards is None:
             n_shards = -(-keys.size // SHARD_MAX_KEYS)
         offsets = shard_offsets(keys, max(int(n_shards), 1))
-        t0 = time.perf_counter()
+        from ..obs.trace import TRACE
         from .parallel_build import build_shard_plexes
+        phases: dict[str, float] = {}
+        t0 = time.perf_counter()
         plexes = build_shard_plexes(
             keys, offsets, eps, workers=int(workers or 1), pool=pool,
-            mp_context=mp_context, **build_kw)
-        if not layers_unify(plexes):
-            # shards tuned apart (mixed radix/CHT, or CHT radix widths that
-            # differ) would leave serving without its one stacked pipeline:
-            # re-tune the CHT shards over radix tables, which always unify
-            plexes = [as_radix(px, **build_kw) for px in plexes]
-        shards = []
-        for s, px in enumerate(plexes):
-            dev = devices[s % len(devices)] if devices else None
-            shards.append(LearnedIndex(plex=px, default_backend=backend,
-                                       block=block, device=dev))
+            mp_context=mp_context, timings=phases, **build_kw)
+        with TRACE.timed("build.assemble", phases):
+            if not layers_unify(plexes):
+                # shards tuned apart (mixed radix/CHT, or CHT radix widths
+                # that differ) would leave serving without its one stacked
+                # pipeline: re-tune the CHT shards over radix tables, which
+                # always unify
+                plexes = [as_radix(px, **build_kw) for px in plexes]
+            shards = []
+            for s, px in enumerate(plexes):
+                dev = devices[s % len(devices)] if devices else None
+                shards.append(LearnedIndex(plex=px, default_backend=backend,
+                                           block=block, device=dev))
         build_s = time.perf_counter() - t0
         snap = cls(keys, eps, offsets, shards, build_s=build_s, epoch=epoch)
-        from ..obs.trace import TRACE
+        snap.build_phases = phases
         if TRACE.enabled:
+            # CPU-seconds summed over the shards, which ran in parallel:
+            # facts about the build, not intervals on any clock
             bs = snap.build_stats
-            TRACE.record("build.spline", bs.spline_s, shards=len(shards))
-            TRACE.record("build.tune", bs.tune_s, shards=len(shards))
-            TRACE.record("build.layer", bs.layer_s, shards=len(shards))
+            for name, cpu_s in (("build.spline", bs.spline_s),
+                                ("build.tune", bs.tune_s),
+                                ("build.layer", bs.layer_s)):
+                TRACE.event(name, cpu_s=cpu_s, shards=len(shards))
         return snap
 
     # -- metadata -----------------------------------------------------------

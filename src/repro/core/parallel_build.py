@@ -142,8 +142,8 @@ def _mp_context(mp_context=None) -> multiprocessing.context.BaseContext:
 
 def iter_built_shards(keys: np.ndarray, offsets: np.ndarray, eps: int, *,
                       workers: int = 1, pool: str = "process",
-                      mp_context=None, **build_kw
-                      ) -> Iterator[tuple[int, PLEX]]:
+                      mp_context=None, timings: dict | None = None,
+                      **build_kw) -> Iterator[tuple[int, PLEX]]:
     """Yield ``(shard_index, PLEX)`` in shard order, building up to
     ``workers`` shards concurrently.
 
@@ -152,71 +152,88 @@ def iter_built_shards(keys: np.ndarray, offsets: np.ndarray, eps: int, *,
     are yielded as soon as each shard *and all its predecessors* are done,
     so a streaming consumer can write shard ``s`` to disk while shards
     ``> s`` are still building. ``workers <= 1`` (or a single shard)
-    degrades to the serial in-process loop — no pool, no transport."""
+    degrades to the serial in-process loop — no pool, no transport.
+
+    ``timings`` gets the wall seconds of ``build.pool`` (the pool started
+    and every task queued with its keys) and ``build.shards`` (the first
+    result awaited to the pool shut down, the consumer's time between
+    yields included); each is also a span while ``TRACE`` is on. Each
+    shard is a ``build.shard`` event carrying its worker's CPU-seconds
+    (``cpu_s``): workers overlap, so that is no interval of the parent's
+    clock."""
+    timings = {} if timings is None else timings
     spans = spans_of(offsets, keys.size)
     if workers <= 1 or len(spans) <= 1 or pool == "serial":
-        for s, (lo, hi) in enumerate(spans):
-            fire(POINT_BUILD_SHARD, shard=s)
-            px = build_plex(keys[lo:hi], eps, **build_kw)
-            if TRACE.enabled:
-                TRACE.record("build.shard", px.stats.total_s,
-                             shard=s, n_keys=hi - lo)
-            yield s, px
+        with TRACE.timed("build.shards", timings):
+            for s, (lo, hi) in enumerate(spans):
+                fire(POINT_BUILD_SHARD, shard=s)
+                px = build_plex(keys[lo:hi], eps, **build_kw)
+                TRACE.event("build.shard", cpu_s=px.stats.total_s,
+                            shard=s, n_keys=hi - lo)
+                yield s, px
         return
-
-    workers = min(int(workers), len(spans))
-    if pool == "thread":
-        ex = concurrent.futures.ThreadPoolExecutor(max_workers=workers)
-        cleanup = lambda: None  # noqa: E731 - trivial no-op pair
-
-        def submit(s: int, lo: int, hi: int):
-            return ex.submit(
-                lambda: (s, build_plex(keys[lo:hi], eps, **build_kw)))
-    elif pool == "process":
-        ctx = _mp_context(mp_context)
-        desc, cleanup = _keys_descriptor(keys, ctx.get_start_method())
-        ex = concurrent.futures.ProcessPoolExecutor(
-            max_workers=workers, mp_context=ctx,
-            initializer=_pool_init, initargs=(desc,))
-
-        def submit(s: int, lo: int, hi: int):
-            return ex.submit(_build_shard_task, s, lo, hi, eps, build_kw,
-                             None if desc else keys[lo:hi])
-    else:
+    if pool not in ("process", "thread"):
         raise ValueError(f"pool must be 'process', 'thread', or 'serial', "
                          f"got {pool!r}")
 
+    workers = min(int(workers), len(spans))
+    cleanup = lambda: None  # noqa: E731 - trivial no-op
+    ex = None
     try:
-        futs = {submit(s, lo, hi): s for s, (lo, hi) in enumerate(spans)}
-        ready: dict[int, PLEX] = {}
-        next_s = 0
-        for fut in concurrent.futures.as_completed(futs):
-            s, px = fut.result()      # a worker failure propagates here
-            if px.keys is None:       # process transport stripped the view
-                lo, hi = spans[s]
-                px.keys = keys[lo:hi]
-            ready[s] = px
-            while next_s in ready:
-                fire(POINT_BUILD_SHARD, shard=next_s)
-                nxt = ready.pop(next_s)
-                if TRACE.enabled:
-                    # worker-side CPU seconds (wall time overlaps shards)
-                    TRACE.record("build.shard", nxt.stats.total_s,
-                                 shard=next_s, pool=pool)
-                yield next_s, nxt
-                next_s += 1
+        with TRACE.timed("build.pool", timings):
+            if pool == "thread":
+                ex = concurrent.futures.ThreadPoolExecutor(
+                    max_workers=workers)
+
+                def submit(s: int, lo: int, hi: int):
+                    return ex.submit(
+                        lambda: (s, build_plex(keys[lo:hi], eps,
+                                               **build_kw)))
+            else:
+                ctx = _mp_context(mp_context)
+                desc, cleanup = _keys_descriptor(keys,
+                                                 ctx.get_start_method())
+                ex = concurrent.futures.ProcessPoolExecutor(
+                    max_workers=workers, mp_context=ctx,
+                    initializer=_pool_init, initargs=(desc,))
+
+                def submit(s: int, lo: int, hi: int):
+                    return ex.submit(_build_shard_task, s, lo, hi, eps,
+                                     build_kw, None if desc else keys[lo:hi])
+            futs = {submit(s, lo, hi): s for s, (lo, hi) in enumerate(spans)}
+        with TRACE.timed("build.shards", timings):
+            ready: dict[int, PLEX] = {}
+            next_s = 0
+            for fut in concurrent.futures.as_completed(futs):
+                s, px = fut.result()      # a worker failure propagates here
+                if px.keys is None:       # process transport stripped it
+                    lo, hi = spans[s]
+                    px.keys = keys[lo:hi]
+                ready[s] = px
+                while next_s in ready:
+                    fire(POINT_BUILD_SHARD, shard=next_s)
+                    nxt = ready.pop(next_s)
+                    TRACE.event("build.shard", cpu_s=nxt.stats.total_s,
+                                shard=next_s, pool=pool)
+                    yield next_s, nxt
+                    next_s += 1
+            ex.shutdown(wait=True)
     finally:
-        ex.shutdown(wait=True, cancel_futures=True)
+        # after an error or an abandoned iteration; a no-op after the
+        # shutdown above
+        if ex is not None:
+            ex.shutdown(wait=True, cancel_futures=True)
         cleanup()
 
 
 def build_shard_plexes(keys: np.ndarray, offsets: np.ndarray, eps: int, *,
                        workers: int = 1, pool: str = "process",
-                       mp_context=None, **build_kw) -> list[PLEX]:
+                       mp_context=None, timings: dict | None = None,
+                       **build_kw) -> list[PLEX]:
     """All shard PLEXes in shard order (the ``Snapshot.build`` fan-out)."""
     return [px for _, px in iter_built_shards(
         keys, offsets, eps, workers=workers, pool=pool,
-        mp_context=mp_context, **build_kw)]
+        mp_context=mp_context, timings=timings, **build_kw)]
 
 
 def build_generation(root, keys: np.ndarray, eps: int, *,

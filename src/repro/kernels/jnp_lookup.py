@@ -33,6 +33,7 @@ pipeline exactly once per index regardless of batch size.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import Any, NamedTuple, Sequence
@@ -40,6 +41,7 @@ from typing import Any, NamedTuple, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.xla_metadata import set_xla_metadata
 
 from ..core.plex import PLEX
 from ..obs.metrics import METRICS
@@ -140,6 +142,18 @@ def _route(sp: StackedPlanes, qhi, qlo):
     return jnp.clip(cnt - 1, 0, sp.n_shards - 1)
 
 
+@contextlib.contextmanager
+def _stage(name: str):
+    """One stage of the stacked pipeline: a ``jax.named_scope`` (the HLO
+    ``op_name`` that ``stage_of_ops`` reads) and the same name as an XLA
+    frontend attribute. JAX's persistent compile cache keys a program by
+    its IR without debug info, ``op_name`` included; the attribute is IR,
+    so the cache never serves this program from an entry compiled with
+    other stage boundaries, or none, whose ``op_name`` would be stale."""
+    with jax.named_scope(name), set_xla_metadata(plex_stage=name):
+        yield
+
+
 def _stacked_pipeline_aux(sp: StackedPlanes, probe: str, qhi, qlo):
     """The stacked pipeline plus its observability by-products.
 
@@ -151,31 +165,44 @@ def _stacked_pipeline_aux(sp: StackedPlanes, probe: str, qhi, qlo):
     approximation analysis needs. Both extras are values the pipeline
     already computes; exposing them costs nothing when untraced (XLA
     dead-code-eliminates unused outputs in the plain wrapper below).
+
+    Each stage runs under a named scope (``_stage``): ``plex.route``,
+    ``plex.segment`` (radix or CHT descent, spline segment search and
+    interpolation up to the window base), ``plex.probe`` (the eps-window
+    probe over ``dhi``/``dlo``) and ``plex.fold`` (clamp and global
+    offset; ``delta_rank_adjust`` too). The scopes are metadata in the
+    HLO and cost nothing at run time; ``StackedJnpPlex.stage_of_ops``
+    reads them back.
     """
-    sid = _route(sp, qhi, qlo)
+    with _stage("plex.route"):
+        sid = _route(sp, qhi, qlo)
     s = sp.static
     la = sp.layer_arrays
-    if sp.kind == "radix":
-        base = stacked_radix_window_base(
-            qhi, qlo, sid, la["table"], la["table_off"], la["shift"],
-            la["p_max"], la["lmin_hi"], la["lmin_lo"], sp.skhi, sp.sklo,
-            sp.spos, sp.n_spline, n_spline_max=sp.n_spline_max,
-            max_win=s["max_win"], eps_eff=sp.eps_eff,
-            n_data_max=sp.n_data_max, window=sp.window, mode=s["mode"])
-    else:
-        bins = jnp.stack([extract_bits(qhi, qlo, lvl * s["r"], s["r"])
-                          for lvl in range(s["levels"])])
-        base = stacked_cht_window_base(
-            qhi, qlo, sid, bins, la["cells"], la["cells_off"], la["delta"],
-            sp.skhi, sp.sklo, sp.spos, sp.n_spline,
-            r=s["r"], levels=s["levels"], delta_max=s["delta_max"],
-            n_spline_max=sp.n_spline_max, eps_eff=sp.eps_eff,
-            n_data_max=sp.n_data_max, window=sp.window, mode=s["mode"])
-    row = sid * jnp.int32(sp.n_data_max)
-    got = probe_lower_bound(qhi, qlo, sp.dhi, sp.dlo, row + base,
-                            window=sp.window, mode=probe)
-    local = jnp.minimum(got - row, jnp.take(sp.n_real, sid))
-    return local + jnp.take(sp.row_off, sid), sid, got - (row + base)
+    with _stage("plex.segment"):
+        if sp.kind == "radix":
+            base = stacked_radix_window_base(
+                qhi, qlo, sid, la["table"], la["table_off"], la["shift"],
+                la["p_max"], la["lmin_hi"], la["lmin_lo"], sp.skhi, sp.sklo,
+                sp.spos, sp.n_spline, n_spline_max=sp.n_spline_max,
+                max_win=s["max_win"], eps_eff=sp.eps_eff,
+                n_data_max=sp.n_data_max, window=sp.window, mode=s["mode"])
+        else:
+            bins = jnp.stack([extract_bits(qhi, qlo, lvl * s["r"], s["r"])
+                              for lvl in range(s["levels"])])
+            base = stacked_cht_window_base(
+                qhi, qlo, sid, bins, la["cells"], la["cells_off"],
+                la["delta"], sp.skhi, sp.sklo, sp.spos, sp.n_spline,
+                r=s["r"], levels=s["levels"], delta_max=s["delta_max"],
+                n_spline_max=sp.n_spline_max, eps_eff=sp.eps_eff,
+                n_data_max=sp.n_data_max, window=sp.window, mode=s["mode"])
+    with _stage("plex.probe"):
+        row = sid * jnp.int32(sp.n_data_max)
+        got = probe_lower_bound(qhi, qlo, sp.dhi, sp.dlo, row + base,
+                                window=sp.window, mode=probe)
+        dist = got - (row + base)
+    with _stage("plex.fold"):
+        local = jnp.minimum(got - row, jnp.take(sp.n_real, sid))
+        return local + jnp.take(sp.row_off, sid), sid, dist
 
 
 def _stacked_pipeline(sp: StackedPlanes, probe: str, qhi, qlo):
@@ -241,10 +268,11 @@ def delta_rank_adjust(qhi, qlo, dkhi, dklo, dcum, *, cap: int):
     dispatch, which is what keeps merged lookups at one dispatch per
     micro-batch.
     """
-    zero = jnp.zeros(qhi.shape, jnp.int32)
-    cnt = probe_lower_bound(qhi, qlo, dkhi, dklo, zero, window=cap,
-                            mode="bisect")
-    return jnp.take(dcum, cnt)
+    with _stage("plex.fold"):
+        zero = jnp.zeros(qhi.shape, jnp.int32)
+        cnt = probe_lower_bound(qhi, qlo, dkhi, dklo, zero, window=cap,
+                                mode="bisect")
+        return jnp.take(dcum, cnt)
 
 
 def _stacked_merged(pipeline, cap: int, sp, qhi, qlo, dkhi, dklo, dcum):
@@ -450,6 +478,14 @@ class StackedJnpPlex:
         armed): full pipeline + the telemetry counter-plane scatter."""
         return self._bind(functools.partial(
             _stacked_counted, self._aux_fn(), self.planes.n_shards, cap))
+
+    def stage_of_ops(self, qhi, qlo) -> dict[str, str]:
+        """``{instruction name: stage}`` of the delta-free serving program
+        for query planes like ``qhi``/``qlo`` (arrays or
+        ``jax.ShapeDtypeStruct``; ``planes.stage_of_hlo`` gives the rule).
+        Compiles the program again: call it on demand, after a measured
+        window, never on the serving path."""
+        return self._fn.stage_of_ops(qhi, qlo)
 
     def _counted_fn(self, cap: int):
         fn = self._counted_fns.get(cap)

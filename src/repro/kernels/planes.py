@@ -42,6 +42,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import re
 from typing import Any, Sequence
 
 import jax
@@ -80,6 +81,39 @@ class BoundPlanes:
 
     def lower(self, *args):
         return self.jitted.lower(self.arrays, *args)
+
+    def stage_of_ops(self, *args) -> dict[str, str]:
+        """``{instruction name: stage}`` of the program compiled for
+        ``args``, from its HLO text (``stage_of_hlo``). It compiles the
+        program again, so call it on demand, never on a serving path."""
+        return stage_of_hlo(self.lower(*args).compile().as_text())
+
+
+UNSCOPED = "(unscoped)"
+STAGE_PREFIX = "plex."
+_INSTR = re.compile(r'^\s*(?:ROOT\s+)?%?([\w.\-]+) = ')
+_OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+
+
+def stage_of_hlo(text: str) -> dict[str, str]:
+    """Map each instruction of an HLO module's text to the device stage it
+    belongs to: the innermost ``plex.*`` ``jax.named_scope`` in its
+    ``op_name`` metadata, or ``UNSCOPED`` for an instruction without one
+    (parameters, copies the compiler inserts). The name is the one a
+    profiler trace gives the instruction's device op (``%fusion.22 =
+    ...``). A fusion carries the ``op_name`` XLA gives it, that of the
+    fused computation's root: a fusion that mixes stages counts wholly
+    toward its root's stage."""
+    out = {}
+    for line in text.splitlines():
+        m = _INSTR.match(line)
+        if m is None:
+            continue
+        op = _OP_NAME.search(line)
+        parts = op.group(1).split("/") if op else ()
+        scoped = [p for p in parts if p.startswith(STAGE_PREFIX)]
+        out[m.group(1)] = scoped[-1] if scoped else UNSCOPED
+    return out
 
 
 def bind_planes(body, planes, fields: Sequence[str]) -> BoundPlanes:

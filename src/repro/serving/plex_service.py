@@ -169,6 +169,7 @@ from ..resilience.errors import (BackendUnavailableError, MergeFailedError,
 from ..resilience.faults import (FAULTS, POINT_BACKEND_DISPATCH,
                                  POINT_MERGE_BUILD, POINT_MERGE_WORKER, fire,
                                  is_injected)
+from ..utils.compile_cache import load_seconds
 from .delta import DELTA_CAP_MIN, DeltaBuffer, next_pow2
 
 __all__ = ["DEFAULT_BLOCK", "DEFAULT_MERGE_THRESHOLD",
@@ -564,6 +565,10 @@ class PlexService:
         self._state = _ServiceState(
             snap, DeltaBuffer(snap.keys, capacity=self._delta_capacity),
             self._make_router(snap))
+        # always-on set-up timings, seconds by phase (the build's phases,
+        # then warmup()'s: planes and the programs it loads); each is also
+        # a span while TRACE is on
+        self.setup_stats: dict[str, float] = dict(snap.build_phases)
         # live per-shard routed-query counts + probe-trip histogram for the
         # *current* epoch, folded from the device counter planes at sync
         # points while METRICS is armed. Per-epoch by design (reset at each
@@ -766,6 +771,19 @@ class PlexService:
         return state.snapshot.stacked_impl(
             backend or self.default_backend,
             block=self.block, probe=self.probe, cache_slots=self.cache_slots)
+
+    def stage_of_ops(self) -> dict[str, str]:
+        """``{instruction name: device stage}`` of the delta-free stacked
+        program this service dispatches (``StackedJnpPlex.stage_of_ops``),
+        for block-shaped query planes placed as the serving path places
+        them; ``{}`` without a stacked path. Compiles the program again:
+        call it on demand, never on the serving path."""
+        st = self.stacked_impl()
+        if st is None:
+            return {}
+        q = jax.ShapeDtypeStruct((self.block,), np.uint32,
+                                 sharding=self._batch_sharding)
+        return st.stage_of_ops(q, q)
 
     @staticmethod
     def _delta_view(state: _ServiceState):
@@ -1105,7 +1123,7 @@ class PlexService:
         if not (METRICS.enabled or TRACE.enabled):
             return self._lookup_chain(q, backend)
         t0 = time.perf_counter()
-        with TRACE.span("serve.lookup", backend=backend, n=q.size):
+        with TRACE.request("serve.lookup", backend=backend, n=q.size):
             out = self._lookup_chain(q, backend)
         if METRICS.enabled:
             dur = time.perf_counter() - t0
@@ -1787,7 +1805,7 @@ class PlexService:
         ticket = LookupTicket(self, q.size)
         if q.size == 0:
             return ticket
-        with TRACE.span("serve.submit", n=q.size), self._lock:
+        with TRACE.request("serve.submit", n=q.size), self._lock:
             if self.max_queue and self._q_len + q.size > self.max_queue:
                 err = QueueFullError(
                     f"submit: queue holds {self._q_len} of "
@@ -2037,28 +2055,42 @@ class PlexService:
             self._lock.release()
 
     def _warm_stacked(self, snap: Snapshot, delta_cap: int | None,
-                      backend: str | None = None) -> bool:
+                      backend: str | None = None,
+                      timings: dict | None = None) -> bool:
         """Compile the exact serving dispatch for ``snap`` — same batch
         sharding layout and cache state as the micro-batch pipeline — plus,
         when ``delta_cap`` is given, the merged variant at that capacity
         (warmed with a zero-weight dummy entry, which leaves every result
         untouched). Does not touch the stats; returns False when the shards
-        did not unify."""
-        st = snap.stacked_impl(backend or self.default_backend,
-                               block=self.block, probe=self.probe,
-                               cache_slots=self.cache_slots)
+        did not unify.
+
+        ``timings`` gets ``warmup.planes`` (the stacked planes built on the
+        host and their transfer issued) and ``warmup.compile.plain`` /
+        ``warmup.compile.merged`` (``jax.monitoring``'s lowering and
+        backend-compile or cache-read seconds of each program), adding to
+        what is there. A ``warmup.compile`` span times each program's whole
+        first call."""
+        timings = {} if timings is None else timings
+        with TRACE.timed("warmup.planes", timings):
+            st = snap.stacked_impl(backend or self.default_backend,
+                                   block=self.block, probe=self.probe,
+                                   cache_slots=self.cache_slots)
         if st is None:
             return False
         qh, ql = split_u64(np.repeat(snap.keys[:1], self.block))
         qhi = jax.device_put(qh, self._batch_sharding)
         qlo = jax.device_put(ql, self._batch_sharding)
-        jax.block_until_ready(st.lookup_planes(qhi, qlo, n_valid=1).out)
+        with TRACE.span("warmup.compile", program="plain"), \
+                load_seconds(timings, "warmup.compile.plain"):
+            jax.block_until_ready(st.lookup_planes(qhi, qlo, n_valid=1).out)
         if delta_cap:
             from ..kernels.planes import build_delta_planes
             dummy = build_delta_planes(snap.keys[:1],
                                        np.zeros(1, np.int64), delta_cap)
-            jax.block_until_ready(
-                st.lookup_planes(qhi, qlo, n_valid=1, delta=dummy).out)
+            with TRACE.span("warmup.compile", program="merged"), \
+                    load_seconds(timings, "warmup.compile.merged"):
+                jax.block_until_ready(
+                    st.lookup_planes(qhi, qlo, n_valid=1, delta=dummy).out)
         if METRICS.enabled:
             st.take_counters()    # warm dispatches are not served traffic
         return True
@@ -2089,7 +2121,8 @@ class PlexService:
                     state.router.warmup(np.uint64(state.snapshot.keys[0]),
                                         cap)
                     return
-                if self._warm_stacked(state.snapshot, cap, backend):
+                if self._warm_stacked(state.snapshot, cap, backend,
+                                      self.setup_stats):
                     return
             for shard in self.shards:
                 shard.warmup(backend)

@@ -12,10 +12,17 @@ importing the library never touches the cache, and neither do the tests.
 """
 from __future__ import annotations
 
+import contextlib
 import os
 import pathlib
 
 CACHE_DIRNAME = ".jax_cache"
+
+# jax.monitoring durations of loading one program: its lowering to MLIR,
+# then either the backend compile or the read from the persistent cache
+LOAD_EVENTS = ("/jax/core/compile/jaxpr_to_mlir_module_duration",
+               "/jax/core/compile/backend_compile_duration",
+               "/jax/compilation_cache/cache_retrieval_time_sec")
 
 
 def enable_compile_cache(checkout: str | os.PathLike) -> pathlib.Path:
@@ -28,3 +35,24 @@ def enable_compile_cache(checkout: str | os.PathLike) -> pathlib.Path:
     path = pathlib.Path(checkout).resolve() / CACHE_DIRNAME
     jax.config.update("jax_compilation_cache_dir", str(path))
     return path
+
+
+@contextlib.contextmanager
+def load_seconds(into: dict, key: str):
+    """Add to ``into[key]`` the seconds that ``jax.monitoring`` reports for
+    loading programs (``LOAD_EVENTS``) while the body runs. Tracing to a
+    jaxpr is left out: jax times nested jits inside their parent, so its
+    durations overlap. The listener is process-global: a compile on
+    another thread meanwhile counts too."""
+    import jax
+
+    def on(event: str, duration: float, **kw) -> None:
+        if event in LOAD_EVENTS:
+            into[key] = into.get(key, 0.0) + duration
+
+    into.setdefault(key, 0.0)
+    jax.monitoring.register_event_duration_secs_listener(on)
+    try:
+        yield
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on)

@@ -29,6 +29,10 @@ The contract under test:
 from __future__ import annotations
 
 import json
+import os
+import pathlib
+import subprocess
+import sys
 import threading
 import time
 
@@ -42,6 +46,7 @@ from repro.obs.export import prometheus_text, write_jsonl
 from repro.obs.metrics import RING_SIZE, Histogram, MetricsRegistry
 from repro.obs.recorder import RECORDER, FlightRecorder
 from repro.obs.slo import SLOSpec, SLOWatchdog, default_slos
+from repro.obs import trace as TRACE_MODULE
 from repro.obs.trace import Tracer, _NULL
 from repro.serving.plex_service import PlexService, ServiceStats
 
@@ -736,6 +741,171 @@ def test_span_sampling_keeps_one_in_n():
     with tr.span("t"):
         pass
     assert len(tr.events()) == 1     # back to full fidelity
+
+
+# -- span ids, requests, the profiler's clock, set-up timings -----------------
+
+def test_span_ids_parents_and_requests():
+    """Every event carries its id and its parent span's id; a request
+    span opens a request id that everything nested under it inherits,
+    and post-hoc records and events take their parent from the open
+    stack."""
+    tr = Tracer()
+    tr.enable()
+    with tr.span("setup"):
+        pass
+    with tr.request("serve.lookup", n=3):
+        with tr.span("serve.dispatch"):
+            tr.record("inner.record", 0.0)
+        tr.event("marker")
+    with tr.span("after"):
+        pass
+    by = {e["name"]: e for e in tr.events()}
+    ids = [e["id"] for e in tr.events()]
+    assert len(set(ids)) == len(ids)
+    lookup = by["serve.lookup"]
+    assert lookup["parent"] is None and lookup["request"] == lookup["id"]
+    assert by["serve.dispatch"]["parent"] == lookup["id"]
+    assert by["inner.record"]["parent"] == by["serve.dispatch"]["id"]
+    assert by["inner.record"]["depth"] == 2
+    assert by["marker"]["parent"] == lookup["id"]
+    for name in ("serve.dispatch", "inner.record", "marker"):
+        assert by[name]["request"] == lookup["id"]
+    for name in ("setup", "after"):
+        assert by[name]["parent"] is None and "request" not in by[name]
+    with tr.request("serve.submit"):
+        pass
+    assert tr.events()[-1]["request"] != lookup["request"]
+
+
+def test_record_parent_began_before_it():
+    """A record's parent is the innermost open span that began before the
+    recorded interval did, so no child starts before its parent."""
+    tr = Tracer()
+    tr.enable()
+    with tr.span("outer"):
+        time.sleep(0.02)
+        with tr.span("inner"):
+            tr.record("long", 0.01)      # began before inner did
+            tr.record("short", 0.0)
+        tr.record("older", 10.0)         # began before outer too
+    by = {e["name"]: e for e in tr.events()}
+    assert by["long"]["parent"] == by["outer"]["id"]
+    assert by["long"]["depth"] == 1
+    assert by["short"]["parent"] == by["inner"]["id"]
+    assert by["older"]["parent"] is None and by["older"]["depth"] == 0
+
+
+def test_disabled_request_and_timed():
+    """Disabled, ``request`` is the shared null context like ``span``, and
+    ``timed`` still adds its seconds to the dict without emitting."""
+    tr = Tracer()
+    assert tr.request("serve.lookup", n=1) is _NULL
+    assert tr.span("serve.dispatch") is _NULL
+    into: dict = {}
+    with tr.timed("phase", into):
+        time.sleep(0.01)
+    with tr.timed("phase", into):
+        pass
+    assert into["phase"] >= 0.01 and tr.events() == []
+    tr.enable()
+    with tr.timed("phase", into, k=1):
+        pass
+    assert [e["name"] for e in tr.events()] == ["phase"]
+
+
+def test_obs_imports_without_jax():
+    """``obs`` imports, and an enabled span records, where jax cannot be
+    imported at all (the annotation is bound only when jax is there)."""
+    code = ("import sys\n"
+            "sys.modules['jax'] = None\n"
+            "import repro.obs as o\n"
+            "o.TRACE.enable()\n"
+            "with o.TRACE.span('x'):\n"
+            "    pass\n"
+            "assert o.TRACE.events()[0]['name'] == 'x'\n")
+    src = pathlib.Path(TRACE_MODULE.__file__).resolve().parents[2]
+    out = subprocess.run([sys.executable, "-c", code],
+                         env=dict(os.environ, PYTHONPATH=str(src)),
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+
+
+def test_serve_spans_on_the_profiler_clock(tmp_path):
+    """With TRACE on, a profiler trace around a lookup holds the program's
+    serve.* spans as host events under their own names: serve.lookup
+    around its staging, dispatch and sync, with no anchor applied."""
+    import jax
+    from jax.profiler import ProfileData
+    keys = _keys(20_000)
+    svc = PlexService(keys, 32, n_shards=2, block=1024)
+    try:
+        svc.warmup()
+        q = np.random.default_rng(3).choice(keys, 3000)
+        TRACE.enable()
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            got = svc.lookup(q)
+        finally:
+            jax.profiler.stop_trace()
+        assert np.array_equal(got, np.searchsorted(keys, q))
+    finally:
+        svc.close()
+    path = next(tmp_path.rglob("*.xplane.pb"))
+    host = [(ev.start_ns, ev.start_ns + ev.duration_ns, ev.name)
+            for plane in ProfileData.from_file(str(path)).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events]
+    by = {}
+    for s, e, name in host:
+        by.setdefault(name, []).append((s, e))
+    assert len(by.get("serve.lookup", ())) == 1
+    lo, hi = by["serve.lookup"][0]
+    for child in ("serve.staging", "serve.dispatch", "serve.sync"):
+        assert len(by.get(child, ())) == 1, sorted(by)
+        s, e = by[child][0]
+        assert lo <= s <= e <= hi
+
+
+def test_build_and_warmup_timings_reconcile():
+    """``setup_stats`` is always on: the build's phases sum to ``build_s``
+    and warm-up names its planes and each program it loads. Traced, the
+    build's per-shard and per-phase CPU-seconds are zero-duration events,
+    the phases are spans, and no span or event begins before its
+    parent."""
+    keys = _keys(60_000)
+    TRACE.enable()
+    svc = PlexService(keys, 32, n_shards=3, build_workers=2, pool="thread",
+                      merge_threshold=64)
+    try:
+        svc.warmup()
+        st = svc.setup_stats
+        build = [st[k] for k in ("build.pool", "build.shards",
+                                 "build.assemble")]
+        assert sum(build) == pytest.approx(svc.build_s, rel=0.05)
+        for k in ("warmup.planes", "warmup.compile.plain",
+                  "warmup.compile.merged"):
+            assert st[k] > 0, k
+    finally:
+        svc.close()
+    evs = TRACE.events()
+    by_id = {e["id"]: e for e in evs}
+    for name in ("build.shard", "build.spline", "build.tune",
+                 "build.layer"):
+        mine = [e for e in evs if e["name"] == name]
+        assert mine and all(e["dur_us"] == 0.0 and e["attrs"]["cpu_s"] >= 0
+                            for e in mine), name
+    shards = next(e for e in evs if e["name"] == "build.shards")
+    assert all(e["parent"] == shards["id"] for e in evs
+               if e["name"] == "build.shard")
+    names = {e["name"] for e in evs}
+    assert {"build.pool", "build.assemble", "warmup.planes",
+            "warmup.compile"} <= names
+    assert {e["attrs"]["program"] for e in evs
+            if e["name"] == "warmup.compile"} == {"plain", "merged"}
+    for e in evs:
+        if e["parent"] is not None:
+            assert e["ts"] >= by_id[e["parent"]]["ts"], e
 
 
 # -- flight recorder ---------------------------------------------------------

@@ -14,7 +14,7 @@ from repro.core.plex import build_plex
 from repro.data import generate
 from repro.kernels.jnp_lookup import JnpPlex, StackedJnpPlex
 from repro.kernels.pairs import join_u64, pair_shr, pair_shr_dyn, split_u64
-from repro.kernels.planes import build_stacked_planes
+from repro.kernels.planes import UNSCOPED, build_stacked_planes, stage_of_hlo
 from repro.serving import PlexService
 
 from conftest import sorted_u64
@@ -148,6 +148,47 @@ def test_single_jit_dispatch_per_microbatch(rng):
     assert svc.stats.batches == 4
     assert svc.stats.drained_batches == 4
     assert svc.stats.inflight_batches == 0
+
+
+@pytest.mark.parametrize("kind", ["radix", "cht"])
+def test_device_stages_named_in_the_program(kind, rng):
+    """Every stage of the stacked pipeline reaches the compiled program
+    under its ``plex.*`` scope, and the scoped program answers exactly
+    (present and absent keys against ``np.searchsorted``)."""
+    keys = sorted_u64(rng, 40_000)
+    offs = np.asarray([0, 13_000, 26_000])
+    plexes = _shard_plexes(keys, offs)
+    if kind == "cht":
+        plexes = [_force_cht(px, 6, 3) for px in plexes]
+    st = StackedJnpPlex.from_plexes(plexes, offs, block=512)
+    assert st is not None and st.planes.kind == kind
+    q = np.concatenate([keys[rng.integers(0, keys.size, 1_500)],
+                        sorted_u64(rng, 1_500)])
+    assert np.array_equal(st.lookup(q), np.searchsorted(keys, q, "left"))
+    qh, ql = (jnp.asarray(a) for a in split_u64(q[:512]))
+    stages = st.stage_of_ops(qh, ql)
+    for stage in ("plex.route", "plex.segment", "plex.probe", "plex.fold"):
+        assert stage in stages.values(), stage
+    assert set(stages.values()) <= {"plex.route", "plex.segment",
+                                    "plex.probe", "plex.fold", UNSCOPED}
+
+
+def test_stage_of_hlo_rule():
+    """The innermost ``plex.*`` scope of an instruction's ``op_name``
+    names its stage; without one, or without metadata, it is unscoped."""
+    text = "\n".join([
+        "ENTRY %main (p: u32[8]) -> s32[8] {",
+        '  %p = u32[8]{0} parameter(0), metadata={op_name="args[0]"}',
+        '  %fusion.3 = s32[8]{0} fusion(%p), kind=kLoop, calls=%f, '
+        'metadata={op_name="jit(traced)/plex.probe/jit(_take)/gather" '
+        'stack_frame_id=4}',
+        '  %copy-start = (u32[8]{0}) copy-start(%p)',
+        '  ROOT %add.1 = s32[8]{0} add(%fusion.3, %fusion.3), '
+        'metadata={op_name="jit(traced)/plex.fold/add"}',
+        "}"])
+    assert stage_of_hlo(text) == {
+        "p": UNSCOPED, "fusion.3": "plex.probe", "copy-start": UNSCOPED,
+        "add.1": "plex.fold"}
 
 
 def test_shard_boundary_absent_keys_exact(rng):

@@ -19,8 +19,10 @@ all import this file. Keep these tests in this one file.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -30,8 +32,9 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.cht import build_cht
 from repro.core.plex import build_plex
+from repro.kernels import jnp_lookup
 from repro.kernels.jnp_lookup import StackedJnpPlex
-from repro.kernels.planes import build_stacked_planes
+from repro.kernels.planes import build_stacked_planes, stage_of_hlo
 from repro.kernels.stacked_pallas import StackedPallasPlex
 
 from conftest import sorted_u64
@@ -146,6 +149,42 @@ def test_jnp_stacked_pipeline_compiles_for_v5e(one_chip, kind, probe,
     # the planes are arguments of the program, never baked-in constants
     assert mem.argument_size_in_bytes > 2 * N_SHARDS * N_DATA_MAX * 4
     assert mem.generated_code_size_in_bytes < 1 << 24
+
+
+def _instructions(hlo: str) -> list[str]:
+    """The program's instructions without their metadata, frontend
+    attributes and instruction numbers."""
+    return [re.sub(r"(?<=[._])\d+\b", "", re.sub(
+                r", (metadata|frontend_attributes)=\{[^}]*\}", "", line))
+            for line in hlo.splitlines() if re.match(r"\s*(ROOT )?%", line)]
+
+
+@pytest.mark.parametrize("variant", ["delta_free", "merged", "cached"])
+def test_device_scopes_are_metadata_only(one_chip, variant, monkeypatch):
+    """The ``plex.*`` stages are named in the v5e program and change
+    nothing else: compiled without them, every instruction, fusion and
+    operand is the same but for numbering. The stage attribute is in the
+    IR that keys JAX's persistent compile cache (debug info, ``op_name``
+    among it, is not), so a cached program without the stages cannot
+    stand in for this one."""
+    def lowered():
+        impl = StackedJnpPlex(planes=_smoke_planes("radix", one_chip),
+                              block=BLOCK, probe="count",
+                              cache_slots=CACHE_SLOTS)
+        fn = {"delta_free": lambda: impl._build_fn(0),
+              "merged": lambda: impl._build_fn(DELTA_CAP),
+              "cached": lambda: impl._build_cached_fn(0)}[variant]()
+        return fn.lower(*_args(variant, one_chip))
+
+    scoped = lowered()
+    monkeypatch.setattr(jnp_lookup, "_stage",
+                        lambda name: contextlib.nullcontext())
+    bare = lowered()
+    assert scoped.as_text(debug_info=False) != bare.as_text(debug_info=False)
+    text = scoped.compile().as_text()
+    assert _instructions(text) == _instructions(bare.compile().as_text())
+    assert {"plex.route", "plex.segment", "plex.probe", "plex.fold"} <= set(
+        stage_of_hlo(text).values())
 
 
 @pytest.mark.xfail(strict=True, raises=NotImplementedError,
