@@ -9,10 +9,13 @@ fixed trip count, the TPU-friendly form inherited from
 ``core.plex.bounded_lower_bound``.
 
 The final data probe has two numerically identical modes
-(``plex_segment_lookup.probe_lower_bound``): the branchless count sweep
-(TPU-idiomatic) and a fixed-trip bisect (2-4x faster on CPU, where the
-window-wide gather is memory-bound); ``default_probe_mode`` picks by
-platform.
+(``plex_segment_lookup.probe_lower_bound``): a fixed-trip bisect, the
+default on every backend, and the branchless count sweep, kept as an
+explicit option. The sweep gathers the whole padded window per lane, the
+bisect ``window.bit_length()`` single keys, and the gather's per-element
+cost decides: bisect is 2-4x faster on CPU, and on a TPU v5e serving 200M
+keys at eps 64 (window 256) the probe takes 239.5 ns a lookup against the
+sweep's 13548, the whole pipeline 364 against 13673.
 
 ``StackedJnpPlex`` is the serving hot path: the shard-major fused layout
 (``planes.StackedPlanes``) runs shard routing (predecessor count over the
@@ -58,8 +61,10 @@ PROBE_MODES = ("count", "bisect")
 
 
 def default_probe_mode() -> str:
-    """Count sweep on vector-unit backends, bisect on cache-hierarchy ones."""
-    return "bisect" if jax.default_backend() == "cpu" else "count"
+    """The fixed-trip bisect, on every backend: each of its rounds gathers
+    one key per lane where the count sweep gathers the whole window, and
+    gathered elements are what the probe pays for on CPU and TPU alike."""
+    return "bisect"
 
 
 def _jnp_pipeline(pp: PlexPlanes, probe: str, qhi, qlo):
@@ -91,7 +96,7 @@ class JnpPlex:
 
     planes: PlexPlanes
     block: int
-    probe: str = "count"
+    probe: str = "bisect"
     _fn: Any = None
 
     @classmethod

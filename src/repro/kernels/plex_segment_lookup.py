@@ -225,11 +225,13 @@ def probe_lower_bound(qhi, qlo, dhi, dlo, base, *, window, mode):
     whose key is >= q (``base + window`` when every window key is < q).
 
     Two numerically identical forms, selected statically:
-      * "count": branchless masked compare-and-popcount over the whole
-        window — one vectorised sweep, the TPU-idiomatic form.
       * "bisect": fixed-trip bounded binary search, ceil(log2(window + 1))
-        single-element gather rounds — wins on cache-hierarchy backends
-        (CPU) where the count sweep's window-wide gather is memory-bound.
+        single-element gather rounds — the default on every backend. At
+        window 256 that is 9 gathers per lane and plane against 256.
+      * "count": branchless masked compare-and-popcount over the whole
+        window — one vectorised sweep, but a window-wide gather per lane,
+        which a large key plane pays per element: 2-4x slower on CPU,
+        57x on a TPU v5e at 200M keys (13548 against 239.5 ns a lookup).
     """
     if mode == "count":
         offs = jnp.arange(window, dtype=jnp.int32)

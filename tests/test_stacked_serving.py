@@ -4,6 +4,7 @@ perf-trajectory diff tool."""
 import dataclasses
 import json
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -12,7 +13,8 @@ from repro.core import BACKENDS, LearnedIndex
 from repro.core.cht import build_cht
 from repro.core.plex import build_plex
 from repro.data import generate
-from repro.kernels.jnp_lookup import JnpPlex, StackedJnpPlex
+from repro.kernels.jnp_lookup import (JnpPlex, StackedJnpPlex,
+                                      default_probe_mode)
 from repro.kernels.pairs import join_u64, pair_shr, pair_shr_dyn, split_u64
 from repro.kernels.planes import UNSCOPED, build_stacked_planes, stage_of_hlo
 from repro.serving import PlexService
@@ -328,6 +330,61 @@ def test_probe_modes_identical(rng):
     assert np.array_equal(got["count"], got["bisect"])
     with pytest.raises(ValueError):
         JnpPlex.from_plex(idx.plex, probe="nope")
+
+
+@pytest.mark.parametrize("platform", ["tpu", "gpu", "cpu"])
+def test_default_probe_is_bisect_on_every_backend(platform, monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    assert default_probe_mode() == "bisect"
+
+
+@pytest.mark.parametrize("platform", ["tpu", "gpu", "cpu"])
+def test_unset_probe_resolves_to_bisect(platform, monkeypatch, rng):
+    """``probe=None`` (what ``PlexService`` passes by default) builds the
+    bisect probe whatever the platform, on both jnp lookup surfaces."""
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    keys = sorted_u64(rng, 20_000)
+    offs = np.asarray([0, 10_000])
+    st = StackedJnpPlex.from_plexes(_shard_plexes(keys, offs), offs,
+                                    block=512, probe=None)
+    assert st is not None and st.probe == "bisect"
+    jp = JnpPlex.from_plex(build_plex(keys, 32), block=512, probe=None)
+    assert jp.probe == "bisect"
+    assert JnpPlex(planes=jp.planes, block=512).probe == "bisect"
+
+
+@pytest.mark.parametrize("kind", ["radix", "cht"])
+@pytest.mark.parametrize("probe", ["count", "bisect"])
+def test_probe_at_clamped_window_edge(probe, kind, rng):
+    """Both probes stay exact where the window base is clamped to
+    ``n_data_max - window``: the last keys of the shards that fill a
+    stacked data row, queries at and past each shard's last key (past the
+    end of the index too), and present keys plus one, as the half-absent
+    traffic sends them."""
+    sizes = [12_800, 9_001, 12_800]       # two shards fill a whole row
+    keys = np.unique(sorted_u64(rng, sum(sizes) + 64))[:sum(sizes)]
+    assert keys.size == sum(sizes)
+    offs = np.cumsum([0] + sizes[:-1])
+    plexes = _shard_plexes(keys, offs)
+    if kind == "cht":
+        plexes = [_force_cht(px, 6, 3) for px in plexes]
+    st = StackedJnpPlex.from_plexes(plexes, offs, block=512, probe=probe)
+    assert st is not None
+    sp = st.planes
+    assert sp.kind == kind and sp.n_data_max == 12_800
+    # a last key's base (its prediction, within eps_eff, less eps_eff) lies
+    # past n_data_max - window: the clamp decides it
+    assert sp.n_data_max - 1 - 2 * sp.eps_eff > sp.n_data_max - sp.window
+    ends = np.cumsum(sizes)
+    tail = np.concatenate([keys[e - sp.window:e] for e in ends])
+    u64_max = np.iinfo(np.uint64).max
+    q = np.concatenate([
+        tail, tail + np.uint64(1), keys[ends - 1] - np.uint64(1),
+        keys[offs], keys[offs] - np.uint64(1),
+        np.asarray([u64_max - 1, u64_max], np.uint64)])
+    want = np.searchsorted(keys, q, side="left")
+    assert np.array_equal(st.lookup(q), want)
+    assert not np.isin(tail + np.uint64(1), keys).any()   # all absent
 
 
 # ------------------------------------------------ bench_diff + zipf ----
